@@ -122,26 +122,15 @@ def fleet_instance(pods: int, hosts: int, n_tasks: int) -> Instance:
                     slot_duration=0.1)
 
 
-def _backends(requested: str) -> list:
-    """Backend legs for one run: both when jax is importable, numpy only
-    otherwise (the artifact then records the trajectory it can measure)."""
-    if requested != "both":
-        return [requested]
-    try:
-        from repro.kernels import ts_plan_device
-
-        return ["numpy", "pallas"] if ts_plan_device.available() else ["numpy"]
-    except Exception:  # noqa: BLE001 — no jax on this runner
-        return ["numpy"]
-
-
 def run(configs=None, backend: str = "both") -> list:
     from repro.kernels import ts_plan
 
     rows = []
     prev = ts_plan.get_backend()
     try:
-        for be in _backends(backend):
+        # ``both`` always includes the device leg: when jax cannot run
+        # it, the run fails instead of dropping the leg.
+        for be in ["numpy", "pallas"] if backend == "both" else [backend]:
             ts_plan.set_backend(be)
             for pods, hosts, n_tasks in (
                 configs if configs is not None else CONFIGS

@@ -275,18 +275,9 @@ def dump_backend_parity(out):
     ``ts_plan`` backend (fused f64 pipeline + ledger mirror): paired
     ``backend_*`` blocks must be byte-identical within one dump — the
     device pipeline's bit-exactness contract, end to end through the
-    scheduler.  Skipped (with a marker block) when jax is unavailable."""
-    from repro.kernels import ts_plan  # noqa: E402
+    scheduler."""
+    from repro.kernels import ts_plan, ts_plan_device  # noqa: E402
 
-    try:
-        from repro.kernels import ts_plan_device  # noqa: E402
-
-        have = ts_plan_device.available()
-    except Exception:  # noqa: BLE001
-        have = False
-    if not have:
-        out.write("== backend_parity_skipped_no_jax\n")
-        return
     pods, hosts, n = CONFIGS[0]
     prev = ts_plan.get_backend()
     try:

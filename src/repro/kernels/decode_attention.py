@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
-
 NEG_INF = -1e30
 _LANES = 128
 
@@ -100,7 +98,7 @@ def flash_decode_bhsd(
             pltpu.VMEM((1, _LANES), jnp.float32),
             pltpu.VMEM((1, hd), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
-
 
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, h_ref, *, ck):
     t0 = pl.program_id(2)
@@ -79,7 +77,7 @@ def mamba_scan_blocked(
         out_specs=pl.BlockSpec((1, ck, bd), lambda b, d, t: (b, t, d)),
         out_shape=jax.ShapeDtypeStruct((bsz, s, d_in), jnp.float32),
         scratch_shapes=[pltpu.VMEM((n, bd), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -24,32 +24,32 @@ escalation rounds) extend the same contract to whole pipelines.
   loop — ``repro.core`` relies on this for the paper-semantics guarantee,
   and every other backend is property-tested against it.
 * ``pallas`` (the **device** backend, forced): a shape-bucketed,
-  compile-cached jax pipeline (``ts_plan_device``).  Off-TPU it runs the
-  fused float64 XLA pipeline (``lax.scan`` sequential cumsum), which is
-  **bit-identical to numpy on any input** — f64 add/mul/div/max are
-  exactly rounded and evaluated in the same order.  On TPU it runs the
-  float32 Pallas kernel (Hillis–Steele prefix sum), which agrees bit-wise
-  on *float64-safe* inputs — values and intermediates exactly
-  representable at both precisions (dyadic fractions of moderate
-  magnitude, pow-2 capacities, integer sizes); under exact arithmetic the
-  summation-order difference between sequential and tree prefix sums
-  vanishes.  ``tests/test_wavefront.py`` and
-  ``tests/test_ts_plan_device.py`` pin both contracts in interpret mode.
-* ``auto`` (the **default**): resolves lazily, and only once a call is
-  large enough (≥ ``_AUTO_PROBE_CELLS`` cells) to possibly justify a
-  device round-trip — smaller calls answer through numpy without ever
-  importing jax.  When a non-CPU jax backend is present the device
-  pipeline becomes the default; on CPU the reference numpy kernel stays
+  compile-cached jax pipeline (``ts_plan_device``) with the
+  device-resident ledger mirror, the same on every platform.  XLA's
+  float64 on the TPU is a pair of float32s and is not binary64, so the
+  device holds every value as its float64 bit pattern and computes with
+  integer round-to-nearest-even add/sub/mul (``lax.scan`` sequential
+  cumsum); the one divide per candidate (the plan end) runs on the host.
+  The result is **bit-identical to numpy on any non-negative input**,
+  on the CPU and on the chip (``chip_smoke.py``).
+* ``auto`` (the **default**): resolves once, at the first planning call
+  of the process.  When a non-CPU jax backend is present the device
+  pipeline plans every call; on CPU the reference numpy kernel stays
   (XLA-on-one-socket cannot beat it), unless
-  ``REPRO_TS_PLAN_AUTO_CELLS=<n>`` opts calls of ≥ n cells in.  With no
-  importable jax, ``auto`` degrades to ``numpy`` silently; ``pallas``
-  raises at first use.
+  ``REPRO_TS_PLAN_AUTO_CELLS=<n>`` opts calls of ≥ n cells in.  Only a
+  missing jax makes ``auto`` answer through numpy; any other failure to
+  start the device raises.  ``pallas`` raises at first use without jax.
+
+:func:`plan_scan_pallas` is the float32 Pallas kernel (Hillis–Steele
+prefix sum).  It is on no planning path: it agrees with numpy bit-wise
+only on *float64-safe* inputs (dyadic fractions of moderate magnitude,
+pow-2 capacities, integer sizes), and its whole-window block exceeds the
+TPU's VMEM at windows of 16,384 slots and more.  Off-TPU it runs in
+interpret mode.
 
 Select with ``set_backend(...)`` or ``REPRO_TS_PLAN_BACKEND=...``.
 ``REPRO_TS_PLAN_MIRROR=1/0`` forces the device-resident ledger mirror on
-or off (default: on for non-CPU platforms — see DESIGN.md §8), and
-``REPRO_TS_PLAN_INTERPRET=1/0`` pins the Pallas kernel's interpret mode
-(default: interpret off-TPU).
+or off (default: on for non-CPU platforms — see DESIGN.md §8).
 
 Both backends are **origin-free**: ``booked`` arrives as an already-
 gathered window (or absolute slots translated against ``base_slot`` right
@@ -59,6 +59,7 @@ bit-identical windows to either backend.
 """
 from __future__ import annotations
 
+import importlib.util
 import os
 from typing import Optional, Sequence, Tuple
 
@@ -126,33 +127,6 @@ def _pad_to(x: np.ndarray, shape) -> np.ndarray:
     return np.pad(x, pads)
 
 
-# -- Pallas kernel interpret mode (cached once per process) ------------------
-
-_INTERPRET: Optional[bool] = None
-
-
-def set_interpret(value: Optional[bool]) -> None:
-    """Pin the Pallas kernel's interpret mode (``None`` = re-derive from
-    the jax backend / ``REPRO_TS_PLAN_INTERPRET`` on next use)."""
-    global _INTERPRET
-    _INTERPRET = value
-
-
-def _interpret_default() -> bool:
-    # jax.default_backend() initializes the platform client — not free,
-    # so the answer is resolved once per process instead of per call.
-    global _INTERPRET
-    if _INTERPRET is None:
-        env = os.environ.get("REPRO_TS_PLAN_INTERPRET")
-        if env is not None:
-            _INTERPRET = env not in ("", "0")
-        else:
-            from ._compat import default_backend
-
-            _INTERPRET = default_backend() != "tpu"
-    return _INTERPRET
-
-
 def plan_scan_pallas(
     booked: np.ndarray,
     caps: np.ndarray,
@@ -166,14 +140,14 @@ def plan_scan_pallas(
     bit-wise on float64-safe inputs (module docstring); lazy jax import so
     the numpy scheduling path never touches jax.  Each padded
     ``(NP, LP, WP)`` shape bucket lowers and compiles **once** (the
-    ``ts_plan_device`` compile cache) and the interpret default is cached
-    module-level; the ``overlay`` layer is folded in on the host (one
-    exact elementwise max) — it feeds the same padded gather, so the
-    kernel body is unchanged."""
+    ``ts_plan_device`` compile cache); ``interpret`` defaults to on
+    exactly when the platform is not a TPU.  The ``overlay`` layer is
+    folded in on the host (one exact elementwise max) — it feeds the same
+    padded gather, so the kernel body is unchanged."""
     from . import ts_plan_device
 
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = ts_plan_device.platform() != "tpu"
     if overlay is not None:
         booked = np.maximum(booked, overlay)
     return ts_plan_device.pallas_scan(
@@ -186,10 +160,8 @@ def plan_scan_pallas(
 _VALID_BACKENDS = ("numpy", "pallas", "auto")
 _backend = os.environ.get("REPRO_TS_PLAN_BACKEND", "auto")
 
-#: ``auto`` probes jax only once a call is big enough to possibly justify
-#: a device round-trip; smaller calls answer through numpy without ever
-#: importing jax (keeps the PEP 562 laziness of ``repro.kernels``).
-_AUTO_PROBE_CELLS = 1 << 15
+#: ``auto``'s resolution, made at the first planning call of the process
+#: (importing ``repro.kernels`` alone never imports jax).
 _auto: Optional[Tuple[bool, int]] = None  # (use device?, min cells)
 
 
@@ -207,13 +179,11 @@ def get_backend() -> str:
 
 
 def _resolve_auto() -> Tuple[bool, int]:
-    try:
-        from . import ts_plan_device
+    if importlib.util.find_spec("jax") is None:
+        return (False, 0)  # no jax installed: auto answers through numpy
+    from . import ts_plan_device
 
-        plat = ts_plan_device.platform()
-    except Exception:  # noqa: BLE001 — no jax: auto degrades to numpy
-        return (False, 0)
-    if plat != "cpu":
+    if ts_plan_device.platform() != "cpu":
         return (True, 0)
     env = os.environ.get("REPRO_TS_PLAN_AUTO_CELLS")
     if env:
@@ -230,8 +200,6 @@ def _use_device(cells: int) -> bool:
         return True
     global _auto
     if _auto is None:
-        if cells < _AUTO_PROBE_CELLS:
-            return False
         _auto = _resolve_auto()
     dev, floor = _auto
     return dev and cells >= floor

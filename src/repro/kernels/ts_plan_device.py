@@ -12,7 +12,9 @@ Three layers live here:
   of two (≥ 8), window widths arrive already exact (the engines escalate
   in powers of 4) — and reused for the rest of the process.  ``stats``
   counts built buckets (``traces``) vs reuses (``cache_hits``);
-  ``bench_sched_scale`` reports the hit rate.
+  ``bench_sched_scale`` reports the hit rate.  Across processes, jax's
+  persistent compilation cache keeps the compiled buckets on an
+  accelerator (:func:`platform` places it).
 
 * **Fused float64 pipelines**: residue → bandwidth → sequential-scan
   cumsum → searchsorted, optionally fused with the wavefront plan-end
@@ -21,8 +23,10 @@ Three layers live here:
   (:func:`col_scan`).  The cumsum is a ``lax.scan`` — a strict
   sequential accumulation, which together with IEEE-exact elementwise
   ops makes every output **bit-identical to the numpy reference on any
-  float64 input**.  Freshly padded input buffers are donated
-  (``donate_argnums``); the mirror array is donated only by the
+  float64 input**.  They are the one device path on every platform: the
+  TPU has no native f64 and XLA emulates it there, so ``chip_smoke.py``
+  holds the chip's schedules byte-identical to the numpy reference.
+  The mirror array is donated (``donate_argnums``) only by the
   operations that consume it (reindex/scatter), never by gathers.
 
 * **Ledger mirror** (:class:`DeviceMirror`): a device-resident copy of
@@ -31,17 +35,21 @@ Three layers live here:
   per-wave gathers read device memory instead of re-uploading the
   window.  See DESIGN.md §8 for the sync/invalidation contract.
 
-On a real TPU the float32 Pallas kernel (:func:`pallas_scan`, also
-compile-cached and jitted here) services ``plan_scan``; the fused f64
-XLA pipelines service every platform, and are what the forced
-``pallas`` backend runs off-TPU so that tier-1 parity holds bit-exactly.
+The float32 Pallas kernel (:func:`pallas_scan`) is off the planning path:
+it is reachable only through ``ts_plan.plan_scan_pallas``, agrees with
+numpy only on float64-safe inputs, and its whole-window block exceeds
+the TPU's VMEM at windows of 16,384 slots and more.
 """
 from __future__ import annotations
 
 import os
+import pathlib
 from typing import Optional, Sequence
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from ..obs import default_registry
 from . import ts_plan
@@ -57,26 +65,35 @@ stats = default_registry().group(
     ("traces", "cache_hits", "mirror_syncs", "mirror_cells", "mirror_uploads"),
 )
 
+#: Default home of jax's persistent compilation cache: a fixed path,
+#: because the directory is part of what a later run must find again.
+CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
+
 _cache: dict = {}
 _platform: Optional[str] = None
 _mirror_flag: Optional[bool] = None
 
 
-def available() -> bool:
-    try:
-        import jax  # noqa: F401
-    except ImportError:
-        return False
-    return True
+def place_compile_cache() -> None:
+    """Keep compiled buckets across processes: where no cache directory
+    was configured (jax reads ``JAX_COMPILATION_CACHE_DIR`` itself), use
+    :data:`CACHE_DIR`; and keep every entry, since the per-bucket
+    programs compile faster than jax's default one-second floor."""
+    if not jax.config.jax_compilation_cache_dir:
+        jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
 
 def platform() -> str:
-    """The default jax platform, resolved once per process."""
+    """The default jax platform, resolved once per process.  Off the CPU
+    the first call also places the persistent compile cache, before any
+    program compiles; XLA:CPU programs are cheap to rebuild and are not
+    kept."""
     global _platform
     if _platform is None:
-        from ._compat import default_backend
-
-        _platform = default_backend()
+        _platform = jax.default_backend()
+        if _platform != "cpu":
+            place_compile_cache()
     return _platform
 
 
@@ -122,111 +139,167 @@ def _bucket(n: int, lo: int = 8) -> int:
     return b
 
 
-def _x64():
-    from jax.experimental import enable_x64
+# -- exact binary64 arithmetic on bit patterns ------------------------------
+#
+# The TPU has no float64 unit: XLA splits an f64 into a pair of f32s, which
+# cannot hold every 53-bit mantissa, so neither f64 storage nor f64
+# arithmetic is IEEE binary64 there.  The device therefore never holds a
+# float64: every value travels as its uint64 bit pattern (exact under
+# integer emulation on every platform) and the pipelines compute with the
+# integer routines below — round-to-nearest-even add, subtract and
+# multiply of non-negative finite operands, the only values the ledger
+# path carries (reserved fractions, capacities, seconds, sizes, times).
+# For non-negative values the bit order is the value order, so max, min
+# and comparisons act on the patterns directly.
 
-    return enable_x64()
+_MANT = (1 << 52) - 1
+_HIDDEN = 1 << 52
+_ONE = 0x3FF0000000000000
+_INF = 0x7FF0000000000000
+
+
+def _bits(x, shape=None) -> np.ndarray:
+    """Float64 values → IEEE-754 bit patterns (uint64), zero-padded to
+    ``shape`` (a zero pattern is +0.0)."""
+    b = np.asarray(x, np.float64).view(np.uint64)
+    return b if shape is None else ts_plan._pad_to(b, shape)
+
+
+def _floats(a, n: int) -> np.ndarray:
+    """Device bit patterns → the first ``n`` rows as float64."""
+    return np.asarray(a)[:n].view(np.float64)
+
+
+def _unpack(x):
+    """Bit patterns → ``(m, e)`` with value ``m * 2**(e - 1075)``;
+    subnormals and zero carry ``e = 1`` and no hidden bit."""
+    e = (x >> 52).astype(jnp.int64)
+    f = x & _MANT
+    return jnp.where(e == 0, f, f | _HIDDEN), jnp.maximum(e, 1)
+
+
+def _shr_jam(x, n):
+    """``x >> n`` with every shifted-out bit OR-ed into bit 0, so that
+    rounding still sees an inexact tail (``n`` is clipped to [0, 63])."""
+    n = jnp.clip(n, 0, 63).astype(jnp.uint64)
+    lost = (x & ((jnp.uint64(1) << n) - 1)) != 0
+    return (x >> n) | lost.astype(jnp.uint64)
+
+
+def _round_pack(m3, e):
+    """Round ``m3`` (mantissa with 3 guard bits, leading bit at most 55)
+    at exponent ``e >= 1`` to nearest-even and pack it.  A rounding carry
+    out of the mantissa lands in the exponent field; overflow gives inf."""
+    low = m3 & 7
+    m = m3 >> 3
+    up = (low > 4) | ((low == 4) & ((m & 1) == 1))
+    bits = ((e - 1).astype(jnp.uint64) << 52) + m + up.astype(jnp.uint64)
+    return jnp.minimum(bits, _INF)
+
+
+def _add(a, b):
+    """Binary64 ``a + b`` for non-negative operands."""
+    mh, eh = _unpack(jnp.maximum(a, b))
+    ml, el = _unpack(jnp.minimum(a, b))
+    s = (mh << 3) + _shr_jam(ml << 3, eh - el)
+    carry = (s >> 56).astype(jnp.int64)
+    s = jnp.where(carry == 1, _shr_jam(s, 1), s)
+    return _round_pack(s, eh + carry)
+
+
+def _sub(a, b):
+    """Binary64 ``a - b`` for ``a >= b >= 0``."""
+    ma, ea = _unpack(a)
+    mb, eb = _unpack(b)
+    s = (ma << 3) - _shr_jam(mb << 3, ea - eb)
+    # Renormalize so bit 55 leads, stopping at the subnormal exponent.
+    sh = jnp.minimum(lax.clz(s).astype(jnp.int64) - 8, ea - 1)
+    s = s << sh.astype(jnp.uint64)
+    return jnp.where(s == 0, jnp.uint64(0), _round_pack(s, ea - sh))
+
+
+def _mul(a, b):
+    """Binary64 ``a * b`` for non-negative operands: the 106-bit mantissa
+    product from 32-bit limbs, then one rounding."""
+    ma, ea = _unpack(a)
+    mb, eb = _unpack(b)
+    za = lax.clz(ma).astype(jnp.int64) - 11  # subnormal operands: normalize
+    zb = lax.clz(mb).astype(jnp.int64) - 11
+    ma, ea = ma << za.astype(jnp.uint64), ea - za
+    mb, eb = mb << zb.astype(jnp.uint64), eb - zb
+    al, ah = ma & 0xFFFFFFFF, ma >> 32
+    bl, bh = mb & 0xFFFFFFFF, mb >> 32
+    ll = al * bl
+    mid = al * bh + ah * bl
+    lo = ll + (mid << 32)
+    hi = ah * bh + (mid >> 32) + (lo < ll).astype(jnp.uint64)
+    top = (hi >> 41).astype(jnp.int64)  # product leads at bit 104 + top
+    k = 49 + top
+    s = (hi << (64 - k).astype(jnp.uint64)) | _shr_jam(lo, k)
+    e = ea + eb - 1023 + top
+    s = jnp.where(e < 1, _shr_jam(s, 1 - e), s)
+    out = _round_pack(s, jnp.maximum(e, 1))
+    return jnp.where((ma == 0) | (mb == 0), jnp.uint64(0), out)
 
 
 def _seq_cumsum(d):
-    """Bit-exact sequential inclusive cumsum along axis 1 (``jnp.cumsum``
-    reduces in tree order and is *not* bit-identical to numpy)."""
-    import jax
-    import jax.numpy as jnp
-
+    """Sequential inclusive cumsum along axis 1 — numpy's order, so the
+    sums are bit-identical (``jnp.cumsum`` reduces in tree order)."""
     def step(c, x):
-        c = c + x
+        c = _add(c, x)
         return c, c
 
-    _, cum = jax.lax.scan(step, jnp.zeros(d.shape[0], d.dtype), d.T)
+    _, cum = lax.scan(step, jnp.zeros(d.shape[0], d.dtype), d.T)
     return cum.T
 
 
-# -- fused float64 scan pipelines -------------------------------------------
+# -- fused scan pipelines (bit patterns in, bit patterns out) ----------------
 
 
-def _scan_tail(bk, cp, sc, szv, cap, has_cap):
-    import jax.numpy as jnp
-
-    resid = 1.0 - jnp.max(bk, axis=1)
-    bw = resid * cp[:, None]
+def _scan_tail(bk, cp, sc, tgt, cap, has_cap):
+    """residue → bandwidth → cumsum → hit count; ``tgt`` holds the
+    patterns of ``max(size - EPS, 0)``, since no cumsum is below zero."""
+    resid = _sub(jnp.uint64(_ONE), jnp.max(bk, axis=1))
+    bw = _mul(resid, cp[:, None])
     if has_cap:
         bw = jnp.minimum(bw, cap)
-    cum = _seq_cumsum(bw * sc)
-    hit = jnp.sum(cum < (szv - EPS)[:, None], axis=1)
+    cum = _seq_cumsum(_mul(bw, sc))
+    hit = jnp.sum(cum < tgt[:, None], axis=1)
     return resid, bw, cum, hit
 
 
-def _end_tail(cum, bw, hit, szslot, szv, t0, dur, w):
-    import jax.numpy as jnp
-
-    ar = jnp.arange(cum.shape[0])
-    hidx = jnp.minimum(hit, w - 1)
-    before = jnp.where(hit > 0, cum[ar, jnp.maximum(hit - 1, 0)], 0.0)
-    t_in = jnp.maximum(t0, (szslot + hit) * dur)
-    end = t_in + (szv - before) / bw[ar, hidx]
-    end = jnp.where(hit < w, end, jnp.inf)
-    end = jnp.where(szv <= 0, t0, end)
-    return end
-
-
 def _donate():
-    # Donating the freshly padded gather buffer saves an allocation on a
-    # real device; on CPU jax cannot use np-backed donations and warns.
+    # Donating the consumed mirror saves an allocation on a real device;
+    # on CPU jax cannot use np-backed donations and warns.
     return (0,) if platform() != "cpu" else ()
 
 
 def _build_scan(NP, L, W, has_cap):
-    import jax
+    def f(bk, cp, sc, tgt, cap):
+        return _scan_tail(bk, cp, sc, tgt, cap, has_cap)
 
-    def f(bk, cp, sc, szv, cap):
-        return _scan_tail(bk, cp, sc, szv, cap, has_cap)
-
-    return jax.jit(f, donate_argnums=_donate())
-
-
-def _build_wave(NP, WL, W, dur):
-    import jax
-
-    def f(bk, cp, sc, szv, szslot, t0):
-        resid, bw, cum, hit = _scan_tail(bk, cp, sc, szv, 0.0, False)
-        end = _end_tail(cum, bw, hit, szslot, szv, t0, dur, W)
-        return resid, bw, cum, hit, end
-
-    return jax.jit(f, donate_argnums=_donate())
+    return jax.jit(f)
 
 
 def _build_wave_mirror(NP, WL, W, Wb, dur):
-    import jax
-    import jax.numpy as jnp
-
-    def f(M, padp, off, cp, fs, szv, szslot, t0):
+    def f(M, padp, off, cp, fs, tgt):
         iota = jnp.arange(W)
         bk = M[padp[:, :, None], off[:, None, None] + iota[None, None, :]]
-        sc = jnp.full((NP, W), dur)
-        sc = sc.at[:, 0].set(fs)
-        resid, bw, cum, hit = _scan_tail(bk, cp, sc, szv, 0.0, False)
-        end = _end_tail(cum, bw, hit, szslot, szv, t0, dur, W)
-        return resid, bw, cum, hit, end
+        sc = jnp.full((NP, W), dur, jnp.uint64).at[:, 0].set(fs)
+        return _scan_tail(bk, cp, sc, tgt, None, False)
 
     return jax.jit(f)  # M is the live mirror: never donated by gathers
 
 
 def _build_col(NP, WL, Wm, Wb):
-    import jax
-    import jax.numpy as jnp
-
-    def f(M, padp, colp, cp, sc, szv):
+    def f(M, padp, colp, cp, sc, tgt):
         bk = M[padp[:, :, None], colp[:, None, :]]
-        return _scan_tail(bk, cp, sc, szv, 0.0, False)
+        return _scan_tail(bk, cp, sc, tgt, None, False)
 
     return jax.jit(f)
 
 
 def _build_select(NC, NS):
-    import jax
-    import jax.numpy as jnp
-
     def f(end, rank, seg):
         emin = jax.ops.segment_min(
             end, seg, num_segments=NS + 1, indices_are_sorted=True
@@ -248,97 +321,77 @@ def _build_select(NC, NS):
     return jax.jit(f)
 
 
-def _pad64(x, shape, dtype=np.float64):
-    return ts_plan._pad_to(np.asarray(x, dtype), shape)
+def _targets(sizes, shape):
+    return _bits(np.maximum(np.asarray(sizes, np.float64) - EPS, 0.0), shape)
+
+
+def _scan_out(outs, n):
+    resid, bw, cum, hit = outs
+    return (
+        _floats(resid, n), _floats(bw, n), _floats(cum, n),
+        np.asarray(hit)[:n],
+    )
 
 
 def plan_scan(booked, caps, secs, sizes, bandwidth_cap=None, overlay=None):
-    """Fused device scan; bit-identical to ``plan_scan_numpy`` off-TPU
-    (float64 pipeline), float64-safe-exact on TPU (Pallas kernel)."""
+    """Fused device scan; bit-identical to ``plan_scan_numpy``."""
     if overlay is not None:
         booked = np.maximum(booked, overlay)
-    if platform() == "tpu":
-        return ts_plan.plan_scan_pallas(booked, caps, secs, sizes, bandwidth_cap)
     n, L, W = booked.shape
     NP = _bucket(n)
-    bk = _pad64(booked, (NP, L, W))
-    cp = _pad64(caps, (NP,))
-    sc = _pad64(secs, (NP, W))
-    sz = _pad64(sizes, (NP,))
     has_cap = bandwidth_cap is not None
     fn = _cached(
         ("scan", NP, L, W, has_cap), lambda: _build_scan(NP, L, W, has_cap)
     )
-    with _x64():
-        resid, bw, cum, hit = fn(
-            bk, cp, sc, sz, 0.0 if bandwidth_cap is None else float(bandwidth_cap)
+    cap = _bits(0.0 if bandwidth_cap is None else float(bandwidth_cap))
+    with jax.enable_x64(True):
+        return _scan_out(
+            fn(
+                _bits(booked, (NP, L, W)), _bits(caps, (NP,)),
+                _bits(secs, (NP, W)), _targets(sizes, (NP,)), cap,
+            ),
+            n,
         )
-        out = (
-            np.asarray(resid)[:n],
-            np.asarray(bw)[:n],
-            np.asarray(cum)[:n],
-            np.asarray(hit)[:n],
-        )
-    return out
 
 
 def wave_scan(ledger, pad, caps, sz, t0c, sizes, w, first_secs):
-    """Device wave pipeline: mirror gather (when live) → scan → plan-end
-    extraction, one fused jit call per shape bucket."""
+    """Device wave pipeline: mirror gather (when live) → scan, one fused
+    jit call per shape bucket; the O(n) plan-end extraction (one divide
+    per candidate) runs on the host, exactly as the reference does."""
     dur = float(ledger.slot_duration)
-    if platform() == "tpu":
-        # f32 kernel path: host gather + Pallas scan + host end extraction.
-        booked = ledger.booked_window(pad, sz, w)
-        n = len(caps)
-        secs = np.full((n, w), dur)
-        secs[:, 0] = first_secs
-        resid, bw, cum, hit = ts_plan.plan_scan_pallas(booked, caps, secs, sizes)
-        end = ts_plan._extract_end(
-            dur, t0c, sizes, sz, np.asarray(cum, np.float64),
-            np.asarray(bw, np.float64), np.asarray(hit, np.int64), w,
-        )
-        return resid, bw, cum, hit, end
-    n, wl = pad.shape
-    NP = _bucket(n)
-    padp = ts_plan._pad_to(np.asarray(pad, np.int64), (NP, wl))
-    szp = ts_plan._pad_to(np.asarray(sz, np.int64), (NP,))
-    t0p = _pad64(t0c, (NP,))
-    szvp = _pad64(sizes, (NP,))
-    cpp = _pad64(caps, (NP,))
     mir = _mirror_for(ledger)
-    if mir is not None:
+    if mir is None:
+        booked = ledger.booked_window(pad, sz, w)
+        secs = np.full((len(caps), w), dur)
+        secs[:, 0] = first_secs
+        resid, bw, cum, hit = plan_scan(booked, caps, secs, sizes)
+    else:
+        n, wl = pad.shape
+        NP = _bucket(n)
+        padp = ts_plan._pad_to(np.asarray(pad, np.int64), (NP, wl))
+        szp = ts_plan._pad_to(np.asarray(sz, np.int64), (NP,))
         ledger._ensure(int(szp.max()) + w - 1)
         mir.sync()
         off = np.maximum(szp - mir.base, 0)  # pad rows clamp to in-bounds
-        fsp = _pad64(first_secs, (NP,))
+        durb = int(_bits(dur))
         fn = _cached(
-            ("wave_m", NP, wl, w, mir.width, dur),
-            lambda: _build_wave_mirror(NP, wl, w, mir.width, dur),
+            ("wave_m", NP, wl, w, mir.width, durb),
+            lambda: _build_wave_mirror(NP, wl, w, mir.width, durb),
         )
-        with _x64():
-            resid, bw, cum, hit, end = fn(
-                mir.arr, padp, off, cpp, fsp, szvp, szp, t0p
+        with jax.enable_x64(True):
+            resid, bw, cum, hit = _scan_out(
+                fn(
+                    mir.arr, padp, off, _bits(caps, (NP,)),
+                    _bits(first_secs, (NP,)), _targets(sizes, (NP,)),
+                ),
+                n,
             )
-            out = tuple(np.asarray(a)[:n] for a in (resid, bw, cum, hit, end))
-        return out
-    booked = ledger.booked_window(pad, sz, w)
-    bk = _pad64(booked, (NP, wl, w))
-    secs = np.full((NP, w), dur)
-    secs[:n, 0] = first_secs
-    fn = _cached(("wave", NP, wl, w, dur), lambda: _build_wave(NP, wl, w, dur))
-    with _x64():
-        resid, bw, cum, hit, end = fn(bk, cpp, secs, szvp, szp, t0p)
-        out = tuple(np.asarray(a)[:n] for a in (resid, bw, cum, hit, end))
-    return out
+    end = ts_plan._extract_end(dur, t0c, sizes, sz, cum, bw, hit, w)
+    return resid, bw, cum, hit, end
 
 
 def col_scan(ledger, pad, cols, caps, secs, sizes):
     """Device compressed-column round for the reroute engine."""
-    if platform() == "tpu":
-        booked = ledger.reserved[
-            pad[:, :, None], (cols - ledger.base_slot)[:, None, :]
-        ]
-        return ts_plan.plan_scan_pallas(booked, caps, secs, sizes)
     mir = _mirror_for(ledger)
     if mir is None:
         booked = ledger.reserved[
@@ -352,37 +405,39 @@ def col_scan(ledger, pad, cols, caps, secs, sizes):
     NP = _bucket(n)
     padp = ts_plan._pad_to(np.asarray(pad, np.int64), (NP, wl))
     colp = ts_plan._pad_to(np.asarray(cols - mir.base, np.int64), (NP, m))
-    cpp = _pad64(caps, (NP,))
-    scp = _pad64(secs, (NP, m))
-    szvp = _pad64(sizes, (NP,))
     fn = _cached(
         ("col", NP, wl, m, mir.width), lambda: _build_col(NP, wl, m, mir.width)
     )
-    with _x64():
-        resid, bw, cum, hit = fn(mir.arr, padp, colp, cpp, scp, szvp)
-        out = tuple(np.asarray(a)[:n] for a in (resid, bw, cum, hit))
-    return out
+    with jax.enable_x64(True):
+        return _scan_out(
+            fn(
+                mir.arr, padp, colp, _bits(caps, (NP,)),
+                _bits(secs, (NP, m)), _targets(sizes, (NP,)),
+            ),
+            n,
+        )
 
 
 def wave_select(
     end: np.ndarray, rank: np.ndarray, counts: Sequence[int]
 ) -> np.ndarray:
     """Fused per-segment argmin of ``(end, rank)`` — three sorted
-    ``segment_min`` passes (min end; min rank among exact-float end ties;
-    the unique position carrying both minima).  Exactly the host loop:
-    float equality is exact and ranks are unique within a segment."""
+    ``segment_min`` passes (min end; min rank among exact end ties; the
+    unique position carrying both minima) over the ends' bit patterns
+    (ends are non-negative or inf).  Exactly the host loop: pattern
+    equality is float equality and ranks are unique within a segment."""
     nc = len(end)
     ns = len(counts)
     NC = _bucket(nc)
     NS = _bucket(ns)
     seg = np.full(NC, NS, np.int64)
     seg[:nc] = np.repeat(np.arange(ns, dtype=np.int64), counts)
-    ep = np.full(NC, np.inf)
-    ep[:nc] = end
+    ep = np.full(NC, _INF, np.uint64)
+    ep[:nc] = _bits(end)
     rp = np.full(NC, np.iinfo(np.int64).max, np.int64)
     rp[:nc] = rank
     fn = _cached(("sel", NC, NS), lambda: _build_select(NC, NS))
-    with _x64():
+    with jax.enable_x64(True):
         win = np.asarray(fn(ep, rp, seg))[:ns]
     starts = np.zeros(ns, np.int64)
     np.cumsum(counts[:-1], out=starts[1:])
@@ -393,11 +448,8 @@ def wave_select(
 
 
 def _build_pallas(NP, LP, WP, W, cap, interpret):
-    import jax
-    import jax.numpy as jnp
     from jax.experimental import pallas as pl
-
-    from ._compat import CompilerParams
+    from jax.experimental.pallas import tpu as pltpu
 
     BN = 8
 
@@ -442,7 +494,7 @@ def _build_pallas(NP, LP, WP, W, cap, interpret):
             jax.ShapeDtypeStruct((NP, WP), jnp.float32),
             jax.ShapeDtypeStruct((NP, 1), jnp.int32),
         ],
-        compiler_params=CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )
     # jit so each bucket traces once (interpret mode re-runs the python
@@ -480,21 +532,17 @@ def pallas_scan(booked, caps, secs, sizes, bandwidth_cap, interpret):
 
 
 def _build_reindex(Win, Wb):
-    import jax
-    import jax.numpy as jnp
-
     def f(a, drop):
         return jnp.take(
-            a, drop + jnp.arange(Wb), axis=1, mode="fill", fill_value=0.0
+            a, drop + jnp.arange(Wb), axis=1, mode="fill", fill_value=0
         )
 
-    # The old mirror array is consumed here: donate it (off-CPU).
-    return jax.jit(f, donate_argnums=_donate())
+    # The old mirror array is consumed here: donate it (off-CPU) when the
+    # width is unchanged, the only case its buffer can be reused.
+    return jax.jit(f, donate_argnums=_donate() if Win == Wb else ())
 
 
 def _build_scatter(Wb, K):
-    import jax
-
     def f(a, r, c, v):
         return a.at[r, c].set(v, mode="drop")
 
@@ -509,7 +557,8 @@ def _mirror_for(ledger):
 
 
 class DeviceMirror:
-    """Device-resident copy of a ledger's live ``reserved`` window.
+    """Device-resident copy of a ledger's live ``reserved`` window, held
+    as float64 bit patterns (uint64) like every device-side value.
 
     The ledger's mutators journal every cell write (``note_flat`` /
     ``note_grid``) with the *final* post-clamp value; :meth:`sync` folds
@@ -581,8 +630,6 @@ class DeviceMirror:
     def sync(self) -> None:
         """Bring the device window up to date with the ledger (journal
         replay, or full re-upload after invalidation / shrink)."""
-        import jax  # noqa: F401
-
         led = self._ledger
         res = led.reserved
         nrows, W = res.shape
@@ -605,7 +652,7 @@ class DeviceMirror:
                 ("reidx", self._width, Wb),
                 lambda: _build_reindex(self._width, Wb),
             )
-            with _x64():
+            with jax.enable_x64(True):
                 arr = fn(arr, np.int64(drop))
         if self._rows:
             rows = np.concatenate(self._rows)
@@ -623,14 +670,14 @@ class DeviceMirror:
                 K = _bucket(sel.size, 64)
                 rp = np.zeros(K, np.int64)
                 cp = np.full(K, Wb, np.int64)  # pad cols drop in-scatter
-                vp = np.zeros(K, np.float64)
+                vp = np.zeros(K, np.uint64)
                 rp[: sel.size] = rows[sel]
                 cp[: sel.size] = cc[sel]
-                vp[: sel.size] = vals[sel]
+                vp[: sel.size] = _bits(vals[sel])
                 fn = _cached(
                     ("scat", Wb, K), lambda: _build_scatter(Wb, K)
                 )
-                with _x64():
+                with jax.enable_x64(True):
                     arr = fn(arr, rp, cp, vp)
                 stats["mirror_cells"] += int(sel.size)
             self._rows.clear()
@@ -642,11 +689,9 @@ class DeviceMirror:
         self._width = Wb
 
     def _upload(self, res, Wb, base) -> None:
-        import jax
-
-        buf = np.zeros((res.shape[0], Wb))
-        buf[:, : res.shape[1]] = res
-        with _x64():
+        buf = np.zeros((res.shape[0], Wb), np.uint64)
+        buf[:, : res.shape[1]] = _bits(res)
+        with jax.enable_x64(True):
             self._arr = jax.device_put(buf)
         self._base = base
         self._width = Wb
@@ -661,4 +706,4 @@ class DeviceMirror:
         """Host copy of the device window, trimmed to the ledger width
         (test hook: must equal ``ledger.reserved`` after ``sync``)."""
         W = self._ledger.reserved.shape[1]
-        return np.asarray(self._arr)[:, :W]
+        return np.asarray(self._arr)[:, :W].view(np.float64)

@@ -1,7 +1,9 @@
-"""Device-backend contract tests: fused f64 pipeline parity, Pallas
+"""Device-backend contract tests: bit-pattern pipeline parity with numpy,
 shape-bucket sweeps (interpret mode — no TPU needed), winner-selection
 tie-breaking, the ledger mirror's journal/sync protocol, the compile
 cache, and the auto-selection rule."""
+from pathlib import Path
+
 import numpy as np
 import pytest
 
@@ -68,6 +70,56 @@ def test_f64_pipeline_overlay_and_cap_combos(cap):
     ref = ts_plan.plan_scan_numpy(booked, caps, secs, sizes, cap, overlay)
     got = ts_plan_device.plan_scan(booked, caps, secs, sizes, cap, overlay)
     _assert_same(ref, got)
+
+
+# -- exact binary64 routines on bit patterns --------------------------------
+
+
+def _operand_pairs(seed, n=1 << 14):
+    """Non-negative finite operand pairs: arbitrary patterns (subnormals
+    and huge exponents included), ledger-like fractions, zeros, and
+    exact and near rounding ties."""
+    rng = np.random.default_rng(seed)
+    top = np.uint64(0x7FF0000000000000)
+
+    def any_pattern():
+        return rng.integers(0, top, size=n, dtype=np.uint64).view(np.float64)
+
+    a = rng.uniform(1.0, 2.0, n) * 2.0 ** rng.integers(-30, 30, n)
+    tie = np.ldexp(rng.integers(1, 64, n).astype(np.float64),
+                   np.frexp(a)[1] - 59)
+    sub = np.ldexp(rng.random(n), -1022)
+    pairs = [
+        (any_pattern(), any_pattern()),
+        (rng.random(n), rng.random(n)),
+        (rng.uniform(0.4, 0.8, n), rng.uniform(1.0, 37.0, n)),
+        (a, tie),
+        (a, a * (1.0 - np.ldexp(rng.random(n), -40))),
+        (sub, sub * rng.random(n)),
+        (sub, rng.random(n)),
+        (np.zeros(n), any_pattern()),
+    ]
+    x = np.concatenate([p[0] for p in pairs])
+    y = np.concatenate([p[1] for p in pairs])
+    return x, y
+
+
+@pytest.mark.parametrize("op", ["add", "sub", "mul"])
+def test_bit_pattern_arithmetic_is_binary64(op):
+    x, y = _operand_pairs(17)
+    if op == "sub":
+        x, y = np.maximum(x, y), np.minimum(x, y)
+    ref_op = {"add": np.add, "sub": np.subtract, "mul": np.multiply}[op]
+    with np.errstate(over="ignore", under="ignore"):
+        ref = ref_op(x, y)
+    fn = jax.jit(getattr(ts_plan_device, f"_{op}"))
+    with jax.enable_x64(True):
+        got = np.asarray(fn(ts_plan_device._bits(x), ts_plan_device._bits(y)))
+    bad = np.flatnonzero(got != ts_plan_device._bits(ref))
+    assert bad.size == 0, (
+        f"{op}({x[bad[0]]!r}, {y[bad[0]]!r}) = "
+        f"{got[bad[0:1]].view(np.float64)[0]!r}, want {ref[bad[0]]!r}"
+    )
 
 
 # -- Pallas kernel (interpret): shape buckets on float64-safe inputs ---------
@@ -295,15 +347,39 @@ def test_wave_and_col_scan_parity_through_mirror():
     _assert_same(ref, got)
 
 
+# -- persistent compile cache placement --------------------------------------
+
+
+@pytest.mark.parametrize("preset", [None, "elsewhere"])
+def test_compile_cache_placement(preset, tmp_path):
+    """An explicitly configured cache directory (``JAX_COMPILATION_CACHE_DIR``
+    lands in this config value) is kept; otherwise the fixed repo-root
+    ``.jax_cache`` is used.  Either way every entry is kept."""
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    want = str(tmp_path / preset) if preset else str(ts_plan_device.CACHE_DIR)
+    try:
+        jax.config.update(keys[0], want if preset else None)
+        ts_plan_device.place_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == want
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+    assert ts_plan_device.CACHE_DIR.parent == Path(__file__).resolve().parents[1]
+
+
 # -- auto rule ---------------------------------------------------------------
 
 
 def test_auto_rule_resolution(monkeypatch):
     monkeypatch.setattr(ts_plan, "_backend", "auto")
-    # Small calls never probe: numpy without touching jax.
+    # The first call resolves the rule, whatever its size: on an
+    # accelerator every call plans on the device.
     monkeypatch.setattr(ts_plan, "_auto", None)
-    assert not ts_plan._use_device(ts_plan._AUTO_PROBE_CELLS - 1)
-    assert ts_plan._auto is None
+    assert ts_plan._use_device(1) == (ts_plan_device.platform() != "cpu")
+    assert ts_plan._auto is not None
     # On CPU the resolved answer is numpy...
     if ts_plan_device.platform() == "cpu":
         assert not ts_plan._use_device(1 << 20)
@@ -318,3 +394,22 @@ def test_auto_rule_resolution(monkeypatch):
     assert not ts_plan._use_device(1 << 30)
     monkeypatch.setattr(ts_plan, "_backend", "pallas")
     assert ts_plan._use_device(1)
+
+
+def test_auto_answers_numpy_only_without_jax(monkeypatch):
+    """A missing jax is the one reason ``auto`` plans on numpy; any other
+    failure to start the device is raised, never swallowed."""
+    real = ts_plan.importlib.util.find_spec
+    monkeypatch.setattr(
+        ts_plan.importlib.util, "find_spec",
+        lambda name, *a: None if name == "jax" else real(name, *a),
+    )
+    assert ts_plan._resolve_auto() == (False, 0)
+    monkeypatch.setattr(ts_plan.importlib.util, "find_spec", real)
+
+    def broken():
+        raise RuntimeError("backend failed to start")
+
+    monkeypatch.setattr(ts_plan_device, "platform", broken)
+    with pytest.raises(RuntimeError, match="failed to start"):
+        ts_plan._resolve_auto()
