@@ -16,18 +16,19 @@ Three layers live here:
   persistent compilation cache keeps the compiled buckets on an
   accelerator (:func:`platform` places it).
 
-* **Fused float64 pipelines**: residue → bandwidth → sequential-scan
-  cumsum → searchsorted, optionally fused with the wavefront plan-end
-  extraction (:func:`wave_scan`) and the per-wave winner selection
-  (:func:`wave_select`), or with the reroute compressed-column gather
-  (:func:`col_scan`).  The cumsum is a ``lax.scan`` — a strict
-  sequential accumulation, which together with IEEE-exact elementwise
-  ops makes every output **bit-identical to the numpy reference on any
-  float64 input**.  They are the one device path on every platform: the
-  TPU has no native f64 and XLA emulates it there, so ``chip_smoke.py``
-  holds the chip's schedules byte-identical to the numpy reference.
-  The mirror array is donated (``donate_argnums``) only by the
-  operations that consume it (reindex/scatter), never by gathers.
+* **Fused binary64 pipelines on bit patterns**: residue → bandwidth →
+  sequential-scan cumsum → hit count, fed by the wavefront's mirror
+  gather (:func:`wave_scan`) or the reroute compressed-column gather
+  (:func:`col_scan`), plus the per-wave winner selection
+  (:func:`wave_select`).  Values travel as ``uint64`` float64 bit
+  patterns and the arithmetic is the integer round-to-nearest-even
+  routines below; the cumsum is a ``lax.scan`` in numpy's order.  Every
+  output is **bit-identical to the numpy reference on any non-negative
+  float64 input**, on every platform — the TPU's own f64 is not
+  binary64, so ``chip_smoke.py`` holds the chip's schedules
+  byte-identical to the numpy reference.  The mirror array is donated
+  (``donate_argnums``) only by the operations that consume it
+  (reindex/scatter), never by gathers.
 
 * **Ledger mirror** (:class:`DeviceMirror`): a device-resident copy of
   ``TimeSlotLedger.reserved`` kept in step by a journal of cell writes
