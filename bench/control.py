@@ -6,10 +6,10 @@ control's.
 For each seed this drives the cell's window on the accelerator exactly
 as ``bench/run.py`` does and prints, as one JSON line:
 
-* ``program``: what the program produced against the binary64
-  reference (the lower reading: it has to be 0);
-* ``control``: the reference computed in float32 put in the program's
-  place, against the same binary64 reference (the upper reading: a
+* ``program``: what the program produced against the configuration's
+  reference in binary64 (the lower reading: it has to be 0);
+* ``control``: that reference computed in float32 put in the program's
+  place, against the same binary64 replay (the upper reading: a
   comparison that cannot tell it apart is no check).
 
 The benchmark's own runs do not run the control.  Exits 2 off a TPU.
@@ -34,19 +34,18 @@ def readings(spec, workload, seed, seconds, t_start, root=ROOT, cfg=None,
     import numpy as np
 
     import harness
-    import reference
 
     sink: dict = {}
     result, _ = harness.run_cell(spec, workload, seed, seconds, False, t_start,
                                  root, cfg, traffic, sink)
     drv = sink["program"]
     t0 = time.perf_counter()
-    low, low_log = harness.replay(drv.ref_fabric, sink["cfg"], drv.dep.workers,
-                                  drv.dep.idle, drv.ops, np.float32)
-    control = reference.compare(low, sink["want"], low_log, sink["want_log"])
+    control = drv.ref.compare(harness.replay(drv, sink["cfg"], np.float32),
+                              sink["want"])
     return {"seed": seed, "attempted": result["attempted"],
             "program": {k: c["value"] for k, c in result["checks"].items()},
-            "control": {k: c["value"] for k, c in harness.checks(control).items()},
+            "control": {k: c["value"] for k, c in
+                        harness.checks(control, drv.ref).items()},
             "control_s": time.perf_counter() - t0}
 
 

@@ -121,11 +121,41 @@ def jobs(dep: Deployment, traffic: dict, seed: int) -> Iterator[Tuple[float, Lis
         yield at, dep.tasks(n)
 
 
-def failing_links(links: Sequence[str], traffic: dict, seed: int) -> Iterator[str]:
-    """The link each failure event takes down: a seeded order of every
-    link whose name starts with the mix's prefix, repeated."""
-    pool = sorted(n for n in links if n.startswith(traffic["events"]["fail_links"]))
+def link_order(links: Sequence[str], traffic: dict, k: int) -> List[str]:
+    """The mix's failing links in one fixed order, the same for every
+    seed.  The pool is every link whose name starts with ``fail_links``,
+    in the fabric's construction order; link ``i`` of it falls in group
+    (sum of the digits of ``i`` in base k/2) mod k/2, and the order is
+    group 0, then group 1, and so on.  In a k-ary fat-tree, whose
+    aggregation-core links are built pod by pod, switch by switch, core
+    by core, each group takes one link of every aggregation switch, pod
+    by pod, its core rotating with pod and switch."""
+    h = k // 2
+    pool = [l for l in links if l.startswith(traffic["events"]["fail_links"])]
+    groups: List[List[str]] = [[] for _ in range(h)]
+    for i, name in enumerate(pool):
+        digits, x = 0, i
+        while x:
+            digits, x = digits + x % h, x // h
+        groups[digits % h].append(name)
+    return [name for g in groups for name in g]
+
+
+def failing_links(links: Sequence[str], traffic: dict, k: int,
+                  seed: int) -> Tuple[List[str], List[str], List[str]]:
+    """``(warm-up, window, traced window)``: the last ``warmup_events``
+    links of :func:`link_order`, its first ``window_events`` and its
+    first ``traced_events``, each in a seeded order, so every seed fails
+    the same links in another order."""
+    order = link_order(links, traffic, k)
+    n_warm, n_win = traffic["warmup_events"], traffic["window_events"]
+    if n_warm + n_win > len(order) or traffic["traced_events"] > n_win:
+        raise ValueError(f"{n_warm} warm-up and {n_win} window events need "
+                         f"distinct links, and the pool has {len(order)}")
     rng = _rng(seed, 4)
-    while True:
-        for i in rng.permutation(len(pool)):
-            yield pool[i]
+
+    def shuffled(names):
+        return [names[i] for i in rng.permutation(len(names))]
+
+    return (shuffled(order[len(order) - n_warm:]), shuffled(order[:n_win]),
+            shuffled(order[:traffic["traced_events"]]))

@@ -3,7 +3,9 @@
 Everything about a cell is found by name: its configuration in
 ``bench/configs/<config>.json``, whose ``fabric`` is built by
 ``bench/fabrics/<fabric>.py`` and whose ``controller`` by
-``bench/controllers/<controller>.py``; its traffic mix in
+``bench/controllers/<controller>.py``, and whose ``reference`` (the
+semantics the program is held to) is ``bench/references/<reference>.py``;
+its traffic mix in
 ``bench/traffic/<mix>.json``, whose ``loop`` (closed, open, failure
 events) is ``bench/loops/<loop>.py``; and each metric's reader in
 ``bench/metrics/<metric>.py``.  A new cell, mix or metric is new files
@@ -11,8 +13,9 @@ plus new entries in ``BENCHMARK.json``; nothing here changes.
 
 Every call into the program is logged as a plain operation.  After the
 window, once the device's peak memory is read and the program's state
-is freed, ``bench/reference.py`` replays the log and every assignment
-and reroute the program produced is compared with its own.
+is freed, the configuration's reference replays the log and what the
+program produced is compared with its own output, each number compared
+under the reference's limit.
 """
 from __future__ import annotations
 
@@ -28,17 +31,11 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 import generator
-import reference
 import trace_reduce
 import tracing
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
-
-#: The numbers compared with the reference, each with its limit: exact
-#: comparisons, so the limit is 0.
-LIMITS = {"assignments_wrong": 0, "reroutes_wrong": 0}
-
 
 # -- finding the pieces by name ---------------------------------------------
 
@@ -92,6 +89,13 @@ def loop_module(name: str, bench: Path = BENCH):
     return module_at(bench / "loops" / f"{name}.py")
 
 
+def reference_module(name: str, bench: Path = BENCH):
+    """The semantics a configuration is held to: ``LIMITS``,
+    ``ONLY_WHERE``, ``program_schedule(ctrl)``, ``replay(ref_fabric, cfg,
+    dep, ops, dtype)`` and ``compare(got, want)``."""
+    return module_at(bench / "references" / f"{name}.py")
+
+
 def applies(metric: dict, workload: str) -> bool:
     return "workloads" not in metric or workload in metric["workloads"]
 
@@ -126,6 +130,7 @@ class Program:
     """The program under test, with every call logged as an operation."""
 
     def __init__(self, cfg: dict, seed: int, bench: Path = BENCH):
+        self.ref = reference_module(cfg["reference"], bench)
         fab_mod = fabric_module(cfg["fabric"], bench)
         self.ref_fabric = fab_mod.reference(cfg)
         fab = fab_mod.program(cfg)
@@ -159,32 +164,17 @@ class Program:
         self.ops.append(("recover_link", name, at))
 
     def counters(self) -> Dict[str, float]:
+        """The device backend's counters and every counter and counter
+        group of the controller's ``repro.obs`` registry."""
         from repro.kernels import ts_plan
 
         out = {f"ts_plan_device.{k}": v for k, v in ts_plan.device_stats().items()}
-        for group in ("wavefront", "reroute"):
-            for k, v in self.ctrl.obs.group(group).items():
+        values = self.ctrl.obs.dump_values()
+        out.update(values["counters"])
+        for group, cells in values["groups"].items():
+            for k, v in cells.items():
                 out[f"{group}.{k}"] = v
         return out
-
-    def schedule(self) -> Dict[int, tuple]:
-        names = self.ctrl.state.ledger.link_names
-        out = {}
-        for rec in self.ctrl.jobs.values():
-            for a in rec.assignments:
-                plan = None
-                if a.transfer is not None:
-                    t = a.transfer
-                    plan = reference.Plan(names(t.links), t.start, t.end,
-                                          t.slot_fracs)
-                out[a.tid] = reference.canon(a.node, a.source, plan, a.start,
-                                             a.finish)
-        return out
-
-    def reroute_log(self) -> List[tuple]:
-        return [(r.flow[2], tuple(r.old_path), tuple(r.new_path),
-                 float(r.delivered), float(r.remaining), float(r.new_end))
-                for r in self.ctrl.reroute_log]
 
 
 # -- set-up shared by the loops (``bench/loops/<loop>.py``) ----------------
@@ -222,6 +212,10 @@ class Window:
 
             opts = jax.profiler.ProfileOptions()
             opts.python_tracer_level = 0
+            # The benchmark's annotations only: the runtime's own host
+            # events share the trace's 2 GB with the device's, which loses
+            # its later operations once the trace is full.
+            opts.host_tracer_level = 1
             self.tracer.install()
             jax.profiler.start_trace(self.trace_dir, profiler_options=opts)
             self._ann = jax.profiler.TraceAnnotation(tracing.PREFIX + "window")
@@ -270,40 +264,40 @@ def device_info() -> dict:
 
 
 def check(drv: Program, cfg: dict) -> Tuple[dict, dict]:
-    """Replay the logged operations in the reference and count what
-    differs.  The program's controller is released before the replay.
-    Returns the counts and what both sides produced."""
-    got, got_log = drv.schedule(), drv.reroute_log()
+    """Replay the logged operations in the configuration's reference and
+    count what differs.  The program's controller is released before the
+    replay.  Returns the counts and what both sides produced."""
+    got = drv.ref.program_schedule(drv.ctrl)
     drv.ctrl = None
     gc.collect()
-    want, want_log = replay(drv.ref_fabric, cfg, drv.dep.workers, drv.dep.idle,
-                            drv.ops)
-    counts = reference.compare(got, want, got_log, want_log)
-    return counts, {"schedule": got, "log": got_log, "want": want,
-                    "want_log": want_log}
+    want = replay(drv, cfg)
+    return drv.ref.compare(got, want), {"got": got, "want": want}
 
 
-def checks(counts: dict) -> dict:
-    """The compared numbers beside their limits; reroutes only where
-    either side logged one (a cell without failures has none to
-    compare)."""
-    return {k: {"value": counts[k], "limit": lim} for k, lim in LIMITS.items()
-            if k != "reroutes_wrong" or counts["reroutes"]}
+def checks(counts: dict, ref) -> dict:
+    """The compared numbers beside their limits.  The comparison has to
+    count every number of the reference's ``LIMITS``; one that its
+    ``ONLY_WHERE`` names is compared only where the count named beside it
+    is above 0 (a cell without failures has no reroute to compare)."""
+    missing = sorted(set(ref.LIMITS) - set(counts))
+    if missing:
+        raise ValueError(f"the reference's comparison did not count {missing}")
+
+    def due(k):
+        return k not in ref.ONLY_WHERE or counts[ref.ONLY_WHERE[k]] > 0
+
+    return {k: {"value": counts[k], "limit": lim} for k, lim in ref.LIMITS.items()
+            if due(k)}
 
 
-def replay(ref_fab, cfg, workers, idle, ops, dtype=np.float64):
-    net = reference.Net(ref_fab["links"], ref_fab["parent"], cfg["k_paths"])
-    ref = reference.Reference(net, workers, idle, cfg["slot_s"],
-                              cfg["policy"].get("multipath", False), dtype)
-    for op in ops:
-        ref.apply(op)
-    return ref.schedule(), ref.log
+def replay(drv: Program, cfg: dict, dtype=np.float64):
+    """The reference's output for the run's operations, in ``dtype``."""
+    return drv.ref.replay(drv.ref_fabric, cfg, drv.dep, drv.ops, dtype)
 
 
-def mean_jct(schedule: Dict[int, tuple], jobs_tasks: Dict[int, List[int]],
-             jobs_at: Dict[int, float]) -> Optional[float]:
-    jcts = [max(schedule[t][3] for t in tids) - jobs_at[j]
-            for j, tids in jobs_tasks.items() if tids]
+def mean_jct(ctrl, jobs_at: Dict[int, float]) -> Optional[float]:
+    jcts = [max(a.finish for a in ctrl.jobs[j].assignments) - at
+            for j, at in jobs_at.items() if ctrl.jobs[j].assignments]
     return statistics.fmean(jcts) if jcts else None
 
 
@@ -335,13 +329,15 @@ def run_cell(spec: dict, workload: str, seed: int, seconds: float, trace: bool,
         w = holder["w"]
         c1 = drv.counters()
         counters = {k: c1[k] - holder["c0"].get(k, 0) for k in c1}
-        red = None
+        red, t_trace = None, [time.perf_counter()]
         if trace:
-            red = trace_reduce.reduce(trace_reduce.load(tdir))
+            loaded = trace_reduce.load(tdir)
+            t_trace.append(time.perf_counter())
+            red = trace_reduce.reduce(loaded, [c[-1] for c in tracer.calls], w.ns[0])
+            t_trace.append(time.perf_counter())
     dev = device_info()
     first = out["first_job"]
-    jobs_at = {j: at for j, at in drv.jobs_at.items() if j >= first}
-    jobs_tasks = {j: [a.tid for a in drv.ctrl.jobs[j].assignments] for j in jobs_at}
+    jct = mean_jct(drv.ctrl, {j: at for j, at in drv.jobs_at.items() if j >= first})
     t_check = time.perf_counter()
     counts, prog = check(drv, cfg)
     if sink is not None:
@@ -363,8 +359,9 @@ def run_cell(spec: dict, workload: str, seed: int, seconds: float, trace: bool,
     if trace:
         dev["busy_s"] = red["busy_s"]
         dev["window_s"] = red["window_s"]
-    compared = checks(counts)
-    correct = all(c["value"] <= c["limit"] for c in compared.values())
+    compared = checks(counts, drv.ref)
+    # A run in which nothing was compared is not shown correct.
+    correct = bool(compared) and all(c["value"] <= c["limit"] for c in compared.values())
     result = {"correct": correct, "attempted": out["attempted"],
               "failed": out["failed"], "metrics": metrics, "device": dev}
     if trace:
@@ -374,16 +371,22 @@ def run_cell(spec: dict, workload: str, seed: int, seconds: float, trace: bool,
         f"window: {rec['window_s']:.6f} s, set-up {rec['setup_s']:.6f} s, "
         f"attempted {out['attempted']}, failed {out['failed']}",
         f"compiles inside the window: {counters.get('ts_plan_device.traces', 0)}",
-        f"mean job completion time of the window's jobs: "
-        f"{mean_jct(prog['schedule'], jobs_tasks, jobs_at)} s (simulated)",
+        f"the loop's counts: {json.dumps({k: v for k, v in out.items() if not isinstance(v, list)})}",
+        f"mean job completion time of the window's jobs: {jct} s (simulated)",
         f"counters over the window: {json.dumps(counters, sort_keys=True)}",
     ]
+    if trace:
+        lines.append(f"trace: written in {t_trace[0] - w.t1:.3f} s, read in "
+                     f"{t_trace[1] - t_trace[0]:.3f} s, reduced in "
+                     f"{t_trace[2] - t_trace[1]:.3f} s; device operations recorded "
+                     f"over {red['window_s']:.3f} s of the {red['traced_s']:.3f} s window")
     if out.get("lag_s"):
         lag = sorted(out["lag_s"])
         lines.append(f"generator lag: median {lag[len(lag) // 2]:.6f} s, "
                      f"max {lag[-1]:.6f} s over {len(lag)} submits")
-    lines.append(f"compared {counts['assignments']} assignments and "
-                 f"{counts['reroutes']} reroutes with the reference "
-                 f"in {t_check:.3f} s")
+    lines.append(f"compared with the reference in {t_check:.3f} s: "
+                 + ", ".join(f"{k} {v}" for k, v in counts.items()))
+    if not compared:
+        lines.append("check: nothing was compared")
     lines += [f"check {k}: {c['value']} (limit {c['limit']})" for k, c in compared.items()]
     return result, lines

@@ -54,14 +54,16 @@ def device_idle_pct(rec: dict) -> Optional[float]:
 
 
 def scan_roofline_pct(rec: dict, scan: str) -> Optional[float]:
-    """Least time the chip could take for the window's ``scan`` calls
-    (their bytes over peak HBM bandwidth) over the device time spent
-    inside those calls outside their mirror syncs."""
+    """Least time the chip could take for the ``scan`` calls of the traced
+    window (their bytes over peak HBM bandwidth) over the device time
+    spent inside those calls outside their mirror syncs."""
     red = rec.get("trace")
     if red is None or not rec.get("calls"):
         return None
     kernel_s = red["kernel_s"].get(scan, 0.0)
-    moved = sum(peaks.scan_bytes(n, l, w) for lab, n, l, w in rec["calls"] if lab == scan)
+    end = rec["span_window"][0] + red["window_s"] * 1e9
+    moved = sum(peaks.scan_bytes(n, l, w) for lab, n, l, w, t in rec["calls"]
+                if lab == scan and t <= end)
     if kernel_s <= 0.0 or moved == 0:
         return None
     bound_s = moved / peaks.peak(rec["device_kind"])["hbm_bytes_per_s"]

@@ -29,6 +29,7 @@ def tiny(workload: str):
         traffic["warmup_jobs"] = 2
     else:
         cfg.update(k=4, backlog_tasks=120)
+        traffic.update(warmup_events=2, window_events=6, traced_events=3)
     return cfg, traffic
 
 

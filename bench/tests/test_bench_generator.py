@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 import generator
+import harness
 
 
 def take(it, n):
@@ -64,10 +65,63 @@ def test_open_mix_offers_the_same_sizes_and_gaps_to_every_seed():
 
 def test_closed_mix_and_failing_links():
     traffic = {"job_tasks": {"fixed": 1024}, "arrival": {"every_s": 0.05},
-               "events": {"fail_links": "ac/"}}
+               "events": {"fail_links": "ac/"}, "warmup_events": 1,
+               "window_events": 2, "traced_events": 1}
     assert take(generator.arrivals(traffic, 4), 3) == [0.0, 0.05, 0.1]
     assert take(generator.job_sizes(traffic, 4), 2) == [1024, 1024]
-    links = ["ac/a", "ac/b", "ea/x", "ac/c"]
-    seq = take(generator.failing_links(links, traffic, 11), 6)
-    assert sorted(seq[:3]) == ["ac/a", "ac/b", "ac/c"] and sorted(seq[3:]) == sorted(seq[:3])
-    assert seq == take(generator.failing_links(links, traffic, 11), 6)
+    links = ["ac/a", "ea/x", "ac/b", "ac/c", "ac/d"]
+    # k = 4: the pool a, b, c, d has binary digit sums 0, 1, 1, 2
+    assert generator.link_order(links, traffic, 4) == ["ac/a", "ac/d", "ac/b", "ac/c"]
+    warm, timed, traced = generator.failing_links(links, traffic, 4, 11)
+    assert warm == ["ac/c"] and sorted(timed) == ["ac/a", "ac/d"]
+    assert traced == ["ac/a"]
+    assert generator.failing_links(links, traffic, 4, 11) == (warm, timed, traced)
+    with pytest.raises(ValueError):  # the warm-up would fail a window link
+        generator.failing_links(links, dict(traffic, window_events=4), 4, 11)
+
+
+def test_every_seed_fails_the_same_links_in_another_order():
+    _cell, cfg, traffic = harness.cell_parts(bench_tiny.SPEC, "fattree_k8.link_storm")
+    links = [l[0] for l in harness.fabric_module(cfg["fabric"]).reference(cfg)["links"]]
+    order = generator.link_order(links, traffic, cfg["k"])
+    pool = [l for l in links if l.startswith(traffic["events"]["fail_links"])]
+    assert sorted(order) == sorted(pool)
+    switches = sorted({l[: len("ac/p0a0")] for l in pool})
+    group = len(pool) // (cfg["k"] // 2)
+    for g in range(0, len(pool), group):  # one link of every aggregation switch
+        assert sorted(l[: len("ac/p0a0")] for l in order[g:g + group]) == switches
+    n_warm, n_win = traffic["warmup_events"], traffic["window_events"]
+    runs = [generator.failing_links(links, traffic, cfg["k"], seed)
+            for seed in (1, 2**31 + 77, 4_000_000_007)]
+    for warm, timed, traced in runs:
+        assert sorted(warm) == sorted(order[-n_warm:])
+        assert sorted(timed) == sorted(order[:n_win])
+        assert sorted(traced) == sorted(order[:traffic["traced_events"]])
+        assert not set(warm) & set(timed)
+    assert runs[0][1] != runs[1][1] != runs[2][1] != runs[0][1]
+
+
+@pytest.mark.parametrize("trace", [False, True])
+def test_storm_window_fails_its_fixed_links_at_test_size(monkeypatch, trace):
+    """The window fails its fixed links once each, and a traced window
+    the first of them, whatever ``--seconds`` asks."""
+    import trace_reduce
+
+    monkeypatch.setattr(trace_reduce, "load", lambda _dir: trace_reduce.Trace(
+        ops={0: [(0, 10**6, "op")]}, spans=[("window", 0, 10**9)]))
+    cfg, traffic = bench_tiny.tiny("fattree_k8.link_storm")
+    failed = {}
+    for seed in (3, 2**31 + 9):
+        sink = {}
+        result, _ = bench_tiny.run("fattree_k8.link_storm", seed=seed, seconds=1e-9,
+                                   trace=trace, sink=sink)
+        assert result["correct"] and result["attempted"] > 0
+        links = [l[0] for l in sink["program"].ref_fabric["links"]]
+        order = generator.link_order(links, traffic, cfg["k"])
+        names = [op[1] for op in sink["program"].ops if op[0] == "fail_link"]
+        window = names[traffic["warmup_events"]:]
+        n = traffic["traced_events" if trace else "window_events"]
+        assert sorted(window) == sorted(order[:n])
+        failed[seed] = window
+    first, second = failed.values()
+    assert first != second

@@ -61,8 +61,8 @@ def test_traced_run_prices_only_scans_that_reach_the_device(monkeypatch, backend
     from repro.kernels import ts_plan
 
     monkeypatch.setattr(trace_reduce, "load", lambda _dir: None)
-    monkeypatch.setattr(trace_reduce, "reduce", lambda _tr: {
-        "window_s": 1.0, "busy_s": 0.5, "kernel_s": {}, "breakdown": {}})
+    monkeypatch.setattr(trace_reduce, "reduce", lambda _tr, *_: {
+        "window_s": 1.0, "traced_s": 1.0, "busy_s": 0.5, "kernel_s": {}, "breakdown": {}})
     monkeypatch.setattr(ts_plan, "_backend", backend)
     seen = []
     install = tracing.Tracer.install
@@ -110,15 +110,37 @@ def test_benchmark_json_keeps_to_the_contract():
         assert set(c["reduced"]) == set(cfg["reduced"])
 
 
-def test_new_cell_mix_and_metric_are_files_and_entries_only(tmp_path):
+#: A reference of its own for a new configuration: every submitted task
+#: placed once, on a worker.
+PLACED_ONCE = (
+    "LIMITS = {'tasks_misplaced': 0}\n"
+    "ONLY_WHERE = {}\n"
+    "def program_schedule(ctrl):\n"
+    "    return sorted((a.tid, a.node) for r in ctrl.jobs.values()\n"
+    "                  for a in r.assignments)\n"
+    "def replay(ref_fab, cfg, dep, ops, dtype=None):\n"
+    "    tids = sorted(t[0] for op in ops if op[0] == 'submit' for t in op[3])\n"
+    "    return tids, set(dep.workers)\n"
+    "def compare(got, want):\n"
+    "    tids, workers = want\n"
+    "    wrong = sum(n not in workers for _, n in got)\n"
+    "    wrong += len(set(tids) ^ {t for t, _ in got}) + len(got) - len(set(got))\n"
+    "    return {'tasks': len(tids), 'tasks_misplaced': wrong}\n")
+
+
+def new_cell_checkout(tmp_path, reference_source: str):
+    """A checkout with a new configuration (with its own reference and
+    controller), a new mix and a new metric: new files and new entries
+    only.  Returns its root and its ``BENCHMARK.json``."""
     root = tmp_path / "checkout"
     shutil.copytree(harness.BENCH, root / "bench",
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
     spec = json.loads(json.dumps(bench_tiny.SPEC))
     # a new configuration, a new mix and a new metric: new files ...
     cfg, traffic = bench_tiny.tiny("hadoop_yahoo.saturate")
-    cfg.update(n_pods=2, hosts_per_pod=6, controller="flat_marked")
+    cfg.update(n_pods=2, hosts_per_pod=6, controller="flat_marked", reference="own")
     (root / "bench" / "configs" / "fleet_small.json").write_text(json.dumps(cfg))
+    (root / "bench" / "references" / "own.py").write_text(reference_source)
     (root / "bench" / "controllers" / "flat_marked.py").write_text(
         "import harness\n"
         "def build(fab, dep, cfg):\n"
@@ -138,16 +160,39 @@ def test_new_cell_mix_and_metric_are_files_and_entries_only(tmp_path):
                                "better": "higher", "bound": 0.25, "source": "host_clock",
                                "workloads": ["fleet_small.sparse"]})
     (root / "BENCHMARK.json").write_text(json.dumps(spec))
-    spec = harness.load_spec(root)
+    return root, harness.load_spec(root)
+
+
+def test_new_cell_mix_and_metric_are_files_and_entries_only(tmp_path):
+    root, spec = new_cell_checkout(tmp_path, PLACED_ONCE)
     sink = {}
     result, _ = harness.run_cell(spec, "fleet_small.sparse", 3, 0.2, False, 0.0, root,
                                  sink=sink)
     assert result["correct"]
+    assert result["checks"] == {"tasks_misplaced": {"value": 0, "limit": 0}}
+    assert sink["counts"]["tasks"] > 0
     assert sink["program"].dep.built_by == "flat_marked"
     assert set(result["metrics"]) == {"jobs_in_window", "setup_s"}
     assert result["metrics"]["jobs_in_window"]["value"] >= 1
     with pytest.raises(FileNotFoundError):
         harness.metric_module("no_such_metric", root / "bench")
+
+
+@pytest.mark.parametrize("compare, only_where", [
+    ("{}", "{}"),  # the comparison counts nothing
+    ("{'tasks': 0, 'tasks_misplaced': 0}", "{'tasks_misplaced': 'tasks'}"),  # none due
+])
+def test_a_run_that_compares_nothing_is_not_correct(tmp_path, compare, only_where):
+    source = (PLACED_ONCE.replace("ONLY_WHERE = {}", f"ONLY_WHERE = {only_where}")
+              + f"def compare(got, want):\n    return {compare}\n")
+    root, spec = new_cell_checkout(tmp_path, source)
+    if compare == "{}":
+        with pytest.raises(ValueError, match="tasks_misplaced"):
+            harness.run_cell(spec, "fleet_small.sparse", 3, 0.2, False, 0.0, root)
+        return
+    result, lines = harness.run_cell(spec, "fleet_small.sparse", 3, 0.2, False, 0.0, root)
+    assert result["correct"] is False and result["checks"] == {}
+    assert lines[-1] == "check: nothing was compared"
 
 
 def test_open_loop_times_each_submit_from_its_due_time(monkeypatch):

@@ -50,9 +50,11 @@ def test_controller_self_time_leaves_out_the_engines():
 
 
 def test_roofline_and_idle_shares_from_the_trace():
-    calls = [("wave_scan", 8, 4, 64), ("wave_scan", 16, 4, 256), ("col_scan", 8, 6, 32)]
+    # the last call starts after the traced window, which ends at 1 s
+    calls = [("wave_scan", 8, 4, 64, 10), ("wave_scan", 16, 4, 256, 900_000_000),
+             ("col_scan", 8, 6, 32, 20), ("wave_scan", 64, 4, 64, 1_000_000_001)]
     moved = 8 * 8 * 64 * 7 + 8 * 16 * 256 * 7
-    rec = {"calls": calls, "device_kind": "TPU v5 lite",
+    rec = {"calls": calls, "device_kind": "TPU v5 lite", "span_window": (0, 2 * 10**9),
            "trace": {"kernel_s": {"wave_scan": 1e-3, "col_scan": 0.0},
                      "busy_s": 0.25, "window_s": 1.0}}
     assert read("wave_scan_roofline.place", rec) == pytest.approx(

@@ -77,3 +77,37 @@ def test_a_trace_without_a_device_plane_is_refused():
     host_only = [p for p in planes() if not p.name.startswith("/device")]
     with pytest.raises(ValueError):
         trace_reduce.reduce(trace_reduce.from_planes(host_only))
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3])
+def test_intersect_is_the_pairwise_overlap_in_one_sweep(seed):
+    import random
+
+    rng = random.Random(seed)
+
+    def intervals(n):
+        return trace_reduce.merge([(a, a + rng.randint(1, 30))
+                                   for a in (rng.randint(0, 1000) for _ in range(n))])
+
+    xs, ys = intervals(60), intervals(80)
+    pairwise = trace_reduce.merge([(max(a, c), min(b, d)) for a, b in xs for c, d in ys
+                                   if min(b, d) > max(a, c)])
+    assert trace_reduce.intersect(xs, ys) == pairwise
+    assert trace_reduce.intersect(ys, xs) == pairwise
+
+
+def test_window_ends_with_the_device_trace_where_the_profiler_stopped_it():
+    # the device's last recorded operation ends at 500
+    trimmed = [NS(name=p.name, lines=[NS(name=l.name, events=[
+        e for e in l.events if not p.name.startswith("/device") or e.start_ns < 400])
+        for l in p.lines]) for p in planes()]
+    tr = trace_reduce.from_planes(trimmed)
+    # no device call after its last operation: a device idle to the end
+    assert trace_reduce.reduce(tr, [20, 300], 0)["window_s"] == pytest.approx(1000e-9)
+    # a call at 700 left no operation: the profiler stopped recording them
+    red = trace_reduce.reduce(tr, [20, 300, 700], 0)
+    assert red["window_s"] == pytest.approx(500e-9)
+    assert red["traced_s"] == pytest.approx(1000e-9)
+    assert red["busy_s"] == pytest.approx(30e-9 + 60e-9 + 200e-9)
+    gaps = red["breakdown"]["idle_gaps"]
+    assert sum(g for _, g in gaps) == pytest.approx(500e-9 - red["busy_s"])

@@ -98,6 +98,19 @@ def overlap(xs: Sequence[Interval], ys: Sequence[Interval]) -> float:
     return total
 
 
+def intersect(xs: Sequence[Interval], ys: Sequence[Interval]) -> List[Interval]:
+    """Intersection of two merged interval lists, merged, in one sweep."""
+    out, j = [], 0
+    for a, b in xs:
+        while j < len(ys) and ys[j][1] <= a:
+            j += 1
+        k = j
+        while k < len(ys) and ys[k][0] < b:
+            out.append((max(a, ys[k][0]), min(b, ys[k][1])))
+            k += 1
+    return merge(out)
+
+
 def clip(intervals: Sequence[Interval], t0: float, t1: float) -> List[Interval]:
     return [(max(a, t0), min(b, t1)) for a, b in intervals if b > t0 and a < t1]
 
@@ -152,9 +165,11 @@ class Innermost:
         return self.labels[i] if i >= 0 else ""
 
 
-def breakdown(tr: Trace, t0: float, t1: float, top: int = 10) -> dict:
+def breakdown(tr: Trace, t0: float, t1: float, per_dev: Dict[int, List[Interval]],
+              top: int = 10) -> dict:
     """The device operations that took most time, by enclosing span, and
-    the longest idle gaps, by the host span open during each."""
+    the longest idle gaps (between ``per_dev``'s busy intervals), by the
+    host span open during each."""
     inner = Innermost([s for s in tr.spans if s[0] != "window"])
     op_s: Dict[str, float] = {}
     gaps: List[Tuple[str, float]] = []
@@ -165,7 +180,7 @@ def breakdown(tr: Trace, t0: float, t1: float, top: int = 10) -> dict:
             key = f"{inner.at(a) or 'no span'}:{name}"
             op_s[key] = op_s.get(key, 0.0) + (min(b, t1) - max(a, t0)) * 1e-9
         edge = t0
-        for a, b in busy(tr, t0, t1)[dev]:
+        for a, b in per_dev[dev]:
             if a > edge:
                 gaps.append((inner.at((edge + a) / 2) or "no span", (a - edge) * 1e-9))
             edge = max(edge, b)
@@ -177,13 +192,24 @@ def breakdown(tr: Trace, t0: float, t1: float, top: int = 10) -> dict:
             "idle_gaps": [[k, v] for k, v in gaps[:top]]}
 
 
-def reduce(tr: Trace) -> dict:
+def reduce(tr: Trace, calls_ns: Sequence[int] = (), ns0: int = 0) -> dict:
     """Window, busy time averaged over devices, and device time inside
-    each label's spans (and inside scans outside their mirror syncs)."""
+    each label's spans (and inside scans outside their mirror syncs).
+
+    ``calls_ns`` are the host-clock starts of the window's device calls
+    and ``ns0`` the window's start on that clock.  Where a call starts
+    after the device's last recorded operation, the profiler stopped
+    recording the device's operations before the window closed, and the
+    window ends with that operation: the time after it would read as
+    idle."""
     t0, t1 = window(tr)
-    per_dev = busy(tr, t0, t1)
-    if not per_dev:
+    full_s = (t1 - t0) * 1e-9
+    ends = [b for ops in tr.ops.values() for a, b, _ in ops if a < t1]
+    if not ends:
         raise ValueError("trace has no device plane with operations")
+    if any(t0 + (c - ns0) > max(ends) for c in calls_ns):
+        t1 = max(ends)
+    per_dev = busy(tr, t0, t1)
     n = len(per_dev)
     busy_s = sum(sum(b - a for a, b in iv) for iv in per_dev.values()) * 1e-9 / n
     labels = sorted({l for l, _, _ in tr.spans} - {"window"})
@@ -196,15 +222,15 @@ def reduce(tr: Trace) -> dict:
     kernel_s = {}
     for scan in ("wave_scan", "col_scan"):
         sp = spans_of(tr.spans, [scan], t0, t1)
-        inside_sync = merge([(max(a, c), min(b, d)) for a, b in sp for c, d in sync
-                             if min(b, d) > max(a, c)])
+        inside_sync = intersect(sp, sync)
         kernel_s[scan] = sum(overlap(iv, sp) - overlap(iv, inside_sync)
                              for iv in per_dev.values()) * 1e-9 / n
     return {
         "window_s": (t1 - t0) * 1e-9,
+        "traced_s": full_s,
         "busy_s": busy_s,
         "devices": n,
         "device_s": device_s,
         "kernel_s": kernel_s,
-        "breakdown": breakdown(tr, t0, t1),
+        "breakdown": breakdown(tr, t0, t1, per_dev),
     }

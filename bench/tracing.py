@@ -31,7 +31,8 @@ def _col_shape(ledger, pad, cols, caps, secs, sizes):
 class Tracer:
     def __init__(self):
         self.spans: List[Tuple[str, int, int]] = []
-        self.calls: List[Tuple[str, int, int, int]] = []  # label, n, L, W
+        #: label, n, L, W and the call's start (``perf_counter_ns``)
+        self.calls: List[Tuple[str, int, int, int, int]] = []
         self._undo: List[Tuple[object, str, object]] = []
 
     def _wrap(self, owner, attr: str, label: str) -> None:
@@ -56,7 +57,7 @@ class Tracer:
         calls = self.calls
 
         def wrapped(*args, **kwargs):
-            calls.append((label,) + shape(*args, **kwargs))
+            calls.append((label,) + shape(*args, **kwargs) + (time.perf_counter_ns(),))
             return orig(*args, **kwargs)
 
         self._set(owner, attr, wrapped)
